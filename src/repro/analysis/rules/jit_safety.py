@@ -58,7 +58,6 @@ _TRACING_CALLERS = frozenset(
         "jax.lax.switch",
         "jax.lax.map",
         "jax.lax.associative_scan",
-        "jax.experimental.shard_map.shard_map",
         "jax.shard_map",
     }
 )

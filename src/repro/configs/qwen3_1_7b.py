@@ -1,7 +1,8 @@
 """qwen3-1.7b — dense GQA with qk-norm.
 
 [hf:Qwen/Qwen3-1.7B; hf] 28L d_model=2048 16H (GQA kv=8) d_ff=6144
-vocab=151936, SwiGLU, qk_norm, head_dim=128, rope theta 1e6.
+vocab=151936, SwiGLU, qk_norm, head_dim=128, rope theta 1e6, tied
+input/output embeddings (config.json "tie_word_embeddings": true).
 """
 from repro.models.config import ModelConfig
 
@@ -18,5 +19,6 @@ CONFIG = ModelConfig(
     qk_norm=True,
     activation="silu",
     rope_theta=1e6,
+    tie_embeddings=True,
     source="hf:Qwen/Qwen3-1.7B",
 )

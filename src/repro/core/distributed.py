@@ -55,12 +55,12 @@ from repro.core.engines import (
     auto_engine_config,
 )
 from repro.core.engines.legacy import resolve_distributed_engine
+from repro.kernels.ops import interpret_default, resolve_impl
 
 __all__ = [
     "DistributedSelection",
     "distributed_select",
     "local_then_merge",
-    "compat_shard_map",
     "make_distributed_extract",
     "ROUND1_ENGINES",
     "normalize_round1_config",
@@ -80,29 +80,32 @@ ROUND1_ENGINES = ("matrix", "features", "sparse", "device")
 def normalize_round1_config(ec: "EngineConfig") -> "EngineConfig":
     """Pin a round-1 config to what the shard_map body actually runs.
 
-    Round-1 bodies always use the jnp kernels — Pallas launches inside
-    shard_map are not supported — so the kernel-impl knobs
-    (``gains_impl`` on features/device, ``impl`` on the sparse graph
-    builder) are rewritten to 'jax' here rather than silently overridden
-    in the body: provenance (``CoresetSelection.engine``, checkpoints,
-    benches) then records the real execution path.  An explicit 'pallas'
-    request warns; the device engine's 'auto' default is pinned silently
-    (it means "whatever runs here").  All other knobs (q, stale_tol,
+    The kernel-impl knobs (``gains_impl`` on features/device, ``impl`` on
+    the sparse graph builder) resolve here rather than inside the body, so
+    provenance (``CoresetSelection.engine``, checkpoints, benches) records
+    the real execution path.  On a TPU backend the Pallas kernels lower
+    inside shard_map (DESIGN.md §6), so ``'auto'`` resolves to
+    ``'pallas'`` and an explicit ``'pallas'`` is honored.  Elsewhere the
+    kernels would run in interpret mode, which shard_map bodies do not
+    support: the knobs pin to ``'jax'`` — silently for ``'auto'``, with a
+    warning for an explicit ``'pallas'``.  All other knobs (q, stale_tol,
     tile_dtype, k, block sizes) are shard_map-safe and honored as given.
     """
     for attr in ("gains_impl", "impl"):
         val = getattr(ec, attr, "jax")
-        if val == "jax":
-            continue
-        if val == "pallas":
-            warnings.warn(
-                f"distributed round 1 runs the jnp kernels; "
-                f"{type(ec).__name__}({attr}='pallas') is pinned to 'jax' "
-                "inside shard_map",
-                UserWarning,
-                stacklevel=3,
-            )
-        ec = dataclasses.replace(ec, **{attr: "jax"})
+        resolved = resolve_impl(val, "jax")
+        if resolved == "pallas" and interpret_default():
+            if val == "pallas":
+                warnings.warn(
+                    f"distributed round 1 runs the jnp kernels off-TPU; "
+                    f"{type(ec).__name__}({attr}='pallas') is pinned to "
+                    "'jax' inside shard_map",
+                    UserWarning,
+                    stacklevel=3,
+                )
+            resolved = "jax"
+        if resolved != val:
+            ec = dataclasses.replace(ec, **{attr: resolved})
     return ec
 
 
@@ -136,28 +139,6 @@ def resolve_round1_config(
     return normalize_round1_config(ec)
 
 
-def compat_shard_map(body, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions, replication checks off (the mapped
-    bodies initialize scan carries from constants).  The entry point moved
-    (jax.experimental.shard_map → jax.shard_map) and the kwarg was renamed
-    (check_rep → check_vma) in separate releases, so each is probed
-    independently."""
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    check_kw = (
-        "check_vma"
-        if "check_vma" in inspect.signature(sm).parameters
-        else "check_rep"
-    )
-    return sm(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{check_kw: False},
-    )
-
-
 def make_distributed_extract(select_fn, mesh: Mesh, axis_name: str = "data"):
     """Data-parallel megabatch proxy extraction (DESIGN.md §9).
 
@@ -180,9 +161,12 @@ def make_distributed_extract(select_fn, mesh: Mesh, axis_name: str = "data"):
     def body(params, batches):
         return jax.lax.all_gather(scan(params, batches), axis_name, tiled=True)
 
+    # check_vma=False here and below: the mapped bodies start scan carries
+    # from constants, which the varying-manual-axes check rejects
     return jax.jit(
-        compat_shard_map(
-            body, mesh=mesh, in_specs=(P(), P(axis_name)), out_specs=P()
+        jax.shard_map(
+            body, mesh=mesh, in_specs=(P(), P(axis_name)), out_specs=P(),
+            check_vma=False,
         )
     )
 
@@ -267,7 +251,7 @@ def _local_round_sparse(feats: jax.Array, r_local: int, cfg: SparseConfig):
     Selection runs on the sparsified objective; γ weights are then exact:
     every local point is assigned to its nearest selected medoid from
     features (an (n_local, r_local) distance block, never (n, n)).  The
-    config arrives with the graph builder pinned to the jnp scan
+    graph builder's ``impl`` arrives resolved for the platform
     (``normalize_round1_config``).
     """
     vals, idx = fl.topk_graph(feats, cfg.k, impl=cfg.impl, block_m=cfg.block_m)
@@ -285,8 +269,9 @@ def _local_round_device(feats: jax.Array, r_local: int, cfg: DeviceConfig):
 
     Exact greedy selections (q=1 or stale_tol=1.0) without a dense
     (n_local, n_local) block; γ weights come straight from the engine's
-    exact blocked assignment.  The config arrives pinned to the jnp sweep
-    (``normalize_round1_config``) — shard_map-safe on every backend.
+    exact blocked assignment.  ``gains_impl`` arrives resolved for the
+    platform (``normalize_round1_config``): the fused Pallas sweep on TPU,
+    the jnp sweep elsewhere.
     """
     res = fl.greedy_fl_device(
         feats, r_local, q=cfg.q, gains_impl=cfg.gains_impl,
@@ -298,7 +283,8 @@ def _local_round_device(feats: jax.Array, r_local: int, cfg: DeviceConfig):
 
 def _local_round_features(feats: jax.Array, r_local: int, cfg: FeaturesConfig):
     """Round 1 on one shard via the matrix-free blocked greedy (§3.4);
-    the config arrives pinned to the jnp sweep (``normalize_round1_config``)."""
+    ``gains_impl`` arrives resolved for the platform
+    (``normalize_round1_config``)."""
     res = fl.greedy_fl_features(
         feats, r_local, gains_impl=cfg.gains_impl, block_n=cfg.block_n
     )
@@ -396,9 +382,7 @@ def local_then_merge(
         )
     ec = engine_config if engine_config is not None else MatrixConfig()
     n_local, _ = feats_sharded.shape
-    # psum of a Python literal constant-folds to the static axis size at
-    # trace time (jax.lax.axis_size only exists on newer jax releases)
-    n_shards = int(jax.lax.psum(1, axis_name))  # repro-lint: disable=jit-host-sync  # psum(1) is a static int at trace time, not a traced value
+    n_shards = jax.lax.axis_size(axis_name)
     check_candidate_counts(
         n_local, n_shards, r_local, r_final, where="local_then_merge"
     )
@@ -466,9 +450,9 @@ def distributed_select(
         axis_name=axis_name, engine_config=engine_config,
         squared_coverage=squared_coverage,
     )
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P(axis_name, None),),
-        out_specs=(P(), P(), P()),
+        out_specs=(P(), P(), P()), check_vma=False,
     )
     idx, w, cov = fn(feats.astype(jnp.float32))
     return DistributedSelection(idx, w, cov)

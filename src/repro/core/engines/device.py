@@ -25,6 +25,7 @@ from repro.core.engines.base import (
     normalize_for_metric,
 )
 from repro.core.engines.registry import register_engine
+from repro.kernels import ops as kops
 
 __all__ = ["DeviceConfig", "DeviceEngine", "greedy_fl_device"]
 
@@ -93,8 +94,7 @@ def greedy_fl_device(
     n, d = feats.shape
     feats = feats.astype(jnp.float32)
     budget = int(min(budget, n))
-    if gains_impl == "auto":
-        gains_impl = "pallas" if jax.default_backend() == "tpu" else "jax"
+    gains_impl = kops.resolve_impl(gains_impl, "jax")
     if gains_impl not in ("pallas", "jax"):
         raise ValueError(f"unknown gains_impl {gains_impl!r}")
     if tile_dtype not in ("float32", "bfloat16"):
@@ -128,8 +128,6 @@ def greedy_fl_device(
         best_idx) partials.  Blocks whose every candidate is chosen/padded
         report best_gain ≤ −1e29 (real gains are ≥ 0)."""
         if gains_impl == "pallas":
-            from repro.kernels import ops as kops  # local; kernels optional
-
             return kops.fl_gains_argmax(
                 feats, feats, cur_max, sq, sq, d_max, chosen,
                 block_n=block_n, block_m=bm, tile_dtype=tile_dtype,

@@ -69,6 +69,7 @@ from repro.core.engines.base import (
     normalize_for_metric,
 )
 from repro.core.engines.registry import register_engine
+from repro.kernels import ops as kops
 
 __all__ = [
     "LVL_UNSET",
@@ -541,8 +542,7 @@ def streaming_result_blocked(
     ``impl``: 'auto' (Pallas on TPU, blocked jnp elsewhere) | 'pallas' |
     'jax' | 'dense' (delegate to the reference path).
     """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "jax"
+    impl = kops.resolve_impl(impl, "jax")
     if impl == "dense":
         return streaming_result(state, feats, budget, d_max=d_max)
     if impl not in ("pallas", "jax"):
@@ -586,8 +586,6 @@ def streaming_result_blocked(
         eidx = jnp.asarray(ordered, jnp.int32)
         ef = feats[eidx]
         if impl == "pallas":
-            from repro.kernels import ops as kops  # lazy: keep import light
-
             gains_o, cur, bv, bi = kops.fl_replay(
                 feats, ef, jnp.ones((m,), bool), jnp.zeros((n,), jnp.float32),
                 d_maxf, block_m=block_m,

@@ -24,9 +24,7 @@ def psum_mean(tree: Any, axis_name: str) -> Any:
 
 def reduce_scatter_mean(x: jax.Array, axis_name: str) -> jax.Array:
     """Reduce-scatter over dim 0 (padded to the axis size), mean semantics."""
-    # psum of a literal constant-folds to the static axis size at trace
-    # time (jax.lax.axis_size only exists on newer jax releases)
-    n = int(jax.lax.psum(1, axis_name))
+    n = jax.lax.axis_size(axis_name)
     pad = (-x.shape[0]) % n
     if pad:
         x = jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))

@@ -57,7 +57,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.distributed import (
     check_candidate_counts,
     check_even_shards,
-    compat_shard_map,
     leaf_round,
     merge_round,
     resolve_round1_config,
@@ -448,8 +447,6 @@ def tree_mesh(topology: TreeTopology, devices=None):
     gathers group the closest devices.  Needs exactly ``n_leaves`` devices
     (pass ``devices`` to sub-select; defaults to ``jax.devices()``, which
     spans processes under ``jax.distributed``)."""
-    from repro.launch.mesh import compat_mesh
-
     if devices is None:
         devices = jax.devices()
     if len(devices) != topology.n_leaves:
@@ -460,12 +457,10 @@ def tree_mesh(topology: TreeTopology, devices=None):
         )
     shape = tuple(reversed(topology.fanouts))
     axes = tuple(reversed(topology.axis_names))
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.sharding.Mesh(
-            np.asarray(devices).reshape(shape), axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    return jax.sharding.Mesh(np.asarray(devices).reshape(shape), axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def _tree_body(
@@ -493,9 +488,7 @@ def _tree_body(
     # global leaf id from the axis coordinates, major → minor
     leaf_id = jnp.zeros((), jnp.int32)
     for ax in reversed(axes):
-        leaf_id = leaf_id * jnp.int32(
-            int(jax.lax.psum(1, ax))
-        ) + jax.lax.axis_index(ax)
+        leaf_id = leaf_id * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
 
     local_idx, local_w = leaf_round(feats_local, r_local, engine_cfg)
     cand_feats = feats_local[local_idx]
@@ -593,9 +586,9 @@ def tree_select_mesh(
     # dim 0 sharded over every level axis, major → minor: global index
     # order is (lvl{L-1}, …, lvl0) row-major, matching the body's leaf_id
     flat_axes = tuple(reversed(topology.axis_names))
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P(flat_axes, None),),
-        out_specs=(P(), P(), P()),
+        out_specs=(P(), P(), P()), check_vma=False,
     )
     idx, w, cov = fn(feats)
     wire = wire_bytes_plan(topology, r_local, r_node, d, compress)

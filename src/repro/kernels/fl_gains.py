@@ -56,11 +56,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import pltpu, tpu_params
-
-_TPU_PARAMS = tpu_params("parallel", "arbitrary")
-_REPLAY_PARAMS = tpu_params("arbitrary", "arbitrary")
+_TPU_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary")
+)
+_REPLAY_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary")
+)
 
 __all__ = ["fl_gains_pallas", "fl_gains_argmax_pallas", "fl_replay_pallas"]
 
@@ -243,13 +246,13 @@ def fl_gains_argmax_pallas(
         ],
         out_specs=[
             pl.BlockSpec((1, block_m), lambda mi, ni: (0, mi)),
-            pl.BlockSpec((1, 1), lambda mi, ni: (0, mi)),
-            pl.BlockSpec((1, 1), lambda mi, ni: (0, mi)),
+            pl.BlockSpec((pl.squeezed, 1, 1), lambda mi, ni: (mi, 0, 0)),
+            pl.BlockSpec((pl.squeezed, 1, 1), lambda mi, ni: (mi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, m), jnp.float32),
-            jax.ShapeDtypeStruct((1, m_blocks), jnp.float32),
-            jax.ShapeDtypeStruct((1, m_blocks), jnp.int32),
+            jax.ShapeDtypeStruct((m_blocks, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((m_blocks, 1, 1), jnp.int32),
         ],
         compiler_params=_TPU_PARAMS,
         interpret=interpret,
@@ -261,7 +264,7 @@ def fl_gains_argmax_pallas(
         sqe.astype(jnp.float32),
         penalty.astype(jnp.float32),
     )
-    return gains[0], bg[0], bi[0]
+    return gains[0], bg[:, 0, 0], bi[:, 0, 0]
 
 
 def _replay_kernel(
@@ -278,7 +281,8 @@ def _replay_kernel(
     the bm lanes — the greedy recurrence is inherently sequential), but the
     similarity tile itself comes from one MXU matmul.  Gains partials are
     written per (ni, mi) block — distinct output blocks, no revisiting —
-    and the host sums the n_blocks partial rows.
+    into an (n_blocks, 1, m) array whose leading axis the BlockSpec
+    squeezes, and the caller sums the n_blocks partial rows.
     """
     mi = pl.program_id(1)
     bn = x_ref.shape[0]
@@ -368,7 +372,8 @@ def fl_replay_pallas(
       cur0: (n, 1) fp32 initial cover state; padded pool rows carry +1e30
         so they contribute 0 to every gain.
     Returns:
-      (gains (n_blocks, m) fp32 partials — sum axis 0 for the totals,
+      (gains (n_blocks, 1, m) fp32 partials — sum axes (0, 1) for the
+       totals,
        cur (n, 1) fp32, best_v (n, 1) fp32, best_i (n, 1) int32).
     """
     n, d = x.shape
@@ -390,13 +395,15 @@ def fl_replay_pallas(
             pl.BlockSpec((block_n, 1), lambda ni, mi: (ni, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_m), lambda ni, mi: (ni, mi)),
+            pl.BlockSpec(
+                (pl.squeezed, 1, block_m), lambda ni, mi: (ni, 0, mi)
+            ),
             pl.BlockSpec((block_n, 1), lambda ni, mi: (ni, 0)),
             pl.BlockSpec((block_n, 1), lambda ni, mi: (ni, 0)),
             pl.BlockSpec((block_n, 1), lambda ni, mi: (ni, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, m), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1, m), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.int32),

@@ -24,14 +24,41 @@ __all__ = [
     "ce_proxy",
     "topk_sim",
     "interpret_default",
+    "resolve_impl",
 ]
 
 _LANE = 128
+# Double-buffered (block_n, d) + (block_m, d) input tiles of the fl_gains
+# kernels must leave room for their fp32 (block_n, block_m) intermediates in
+# the 16 MiB of VMEM Mosaic scopes to a kernel by default (v5e).
+_VMEM_TILE_BYTES = 12 * 2**20
 
 
 def interpret_default() -> bool:
     """Pallas interpret mode unless running on a real TPU."""
     return jax.default_backend() != "tpu"
+
+
+def resolve_impl(impl: str, fallback: str) -> str:
+    """``'auto'`` → ``'pallas'`` where the kernels compile for the chip
+    (a TPU backend), else ``fallback``; any other value passes through."""
+    if impl != "auto":
+        return impl
+    return fallback if interpret_default() else "pallas"
+
+
+def _fit_tiles(bn: int, bm: int, d: int, itemsize: int) -> tuple[int, int]:
+    """Halve the larger tile until both input tiles, double-buffered, fit
+    ``_VMEM_TILE_BYTES`` at feature width ``d`` (e.g. (512, 2048) → (512,
+    256) for fp32 at d = 2048, → (512, 1024) for bf16)."""
+    while 2 * (bn + bm) * d * itemsize > _VMEM_TILE_BYTES:
+        if bm >= bn and bm > _LANE:
+            bm //= 2
+        elif bn > 8:
+            bn //= 2
+        else:
+            break
+    return bn, bm
 
 
 def _pad_dim(a: jax.Array, axis: int, mult: int, value: float = 0.0) -> jax.Array:
@@ -73,6 +100,7 @@ def fl_gains(
     m = e.shape[0]
     bn = min(block_n, max(_LANE, 1 << (n - 1).bit_length()))
     bm = min(block_m, max(_LANE, 1 << (m - 1).bit_length()))
+    bn, bm = _fit_tiles(bn, bm, -(-d // _LANE) * _LANE, 4)
     xp = _pad_dim(_pad_dim(x, 0, bn), 1, _LANE)
     ep = _pad_dim(_pad_dim(e, 0, bm), 1, _LANE)
     madj = d_max - cur_max.astype(jnp.float32)
@@ -143,6 +171,7 @@ def fl_gains_argmax(
     m = e.shape[0]
     bn = min(block_n, max(_LANE, 1 << (n - 1).bit_length()))
     bm = min(block_m, max(_LANE, 1 << (m - 1).bit_length()))
+    bn, bm = _fit_tiles(bn, bm, -(-d // _LANE) * _LANE, td.itemsize)
     xp = _pad_dim(_pad_dim(x.astype(td), 0, bn), 1, _LANE)
     ep = _pad_dim(_pad_dim(e.astype(td), 0, bm), 1, _LANE)
     madj = d_max - cur_max.astype(jnp.float32)
@@ -204,6 +233,7 @@ def fl_replay(
     sqe = jnp.sum(e * e, axis=1)
     bn = min(block_n, max(8, 1 << (n - 1).bit_length()))
     bm = min(block_m, max(_LANE, 1 << max(m - 1, 0).bit_length()))
+    bn, bm = _fit_tiles(bn, bm, -(-d // _LANE) * _LANE, 4)
     xp = _pad_dim(_pad_dim(x, 0, bn), 1, _LANE)
     ep = _pad_dim(_pad_dim(e, 0, bm), 1, _LANE)
     sqxp = _pad_dim(sqx.reshape(n, 1), 0, bn)
@@ -220,7 +250,7 @@ def fl_replay(
         block_n=bn, block_m=bm, interpret=interpret,
     )
     return (
-        jnp.sum(gains, axis=0)[:m],
+        jnp.sum(gains, axis=(0, 1))[:m],
         cur[:n, 0],
         bv[:n, 0],
         bi[:n, 0],

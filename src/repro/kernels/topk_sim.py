@@ -36,10 +36,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import tpu_params
-
-_TPU_PARAMS = tpu_params("parallel", "arbitrary")
+_TPU_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary")
+)
 
 __all__ = ["topk_sim_pallas"]
 

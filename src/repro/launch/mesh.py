@@ -11,26 +11,19 @@ dry-run must set XLA_FLAGS before *any* jax initialization.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_host_mesh", "compat_mesh"]
-
-
-def compat_mesh(shape, axes) -> jax.sharding.Mesh:
-    """jax.make_mesh across jax versions: AxisType.Auto exists only ≥ 0.6;
-    older releases reject the kwarg (and are implicitly-auto anyway)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+__all__ = ["make_production_mesh", "make_host_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """1-device mesh with the production axis names (CPU tests)."""
-    return compat_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh(
+        (1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )

@@ -34,6 +34,7 @@ import jax
 import numpy as np
 
 from repro.configs.registry import ARCHS, get_config, smoke_config
+from repro.launch.cache import init_compile_cache
 from repro.models import init_params
 from repro.serve import greedy_generate
 
@@ -111,7 +112,7 @@ def _serve_coreset(args, stdin=None, stdout=None) -> None:
             reply({"ok": False, "error": f"{type(e).__name__}: {e}"})
 
 
-def main(argv=None) -> None:
+def main(argv=None, stdin=None, stdout=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS))
     ap.add_argument("--smoke", action="store_true")
@@ -141,9 +142,10 @@ def main(argv=None) -> None:
                          "serving the installed selection and replies with "
                          "a craig_refresh_failed event")
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     if args.coreset:
-        _serve_coreset(args)
+        _serve_coreset(args, stdin=stdin, stdout=stdout)
         return
     if args.arch is None:
         ap.error("--arch is required unless --coreset is given")

@@ -26,6 +26,7 @@ import numpy as np
 from repro.configs.registry import ARCHS, get_config, smoke_config
 from repro.core.craig import CraigConfig
 from repro.data.synthetic import TokenStream
+from repro.launch.cache import init_compile_cache
 from repro.models import init_params
 from repro.optim import adamw, warmup_cosine
 from repro.train import Trainer, TrainerConfig
@@ -47,6 +48,7 @@ def main() -> None:
     ap.add_argument("--select-every", type=int, default=1)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    init_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.frontend != "tokens":

@@ -11,10 +11,12 @@ Launch line (one per process)::
         --coordinator 127.0.0.1:8476 --num-processes 2 --process-id $i \
         --fanouts 2 --n 256 --d 32 --r-local 8 --r-final 10
 
-On CPU the driver is ``tree_select_processes`` (KV-store wire — XLA CPU
-has no cross-process collectives); pass ``--driver mesh`` on TPU/GPU
-pods to run the single-program ``tree_select_mesh`` over the global
-device mesh instead.
+On a TPU host, one process drives all local chips with ``--driver mesh``
+(the single-program ``tree_select_mesh`` over the device mesh; across
+hosts, one such process per host).  ``--driver processes`` (one JAX
+process per leaf, ``tree_select_processes`` over the KV-store wire) is
+for CPU fleets only: XLA CPU has no cross-process collectives, and on a
+TPU host a second process cannot reach a chip the first one holds.
 """
 from __future__ import annotations
 
@@ -93,6 +95,10 @@ def main(argv: list[str] | None = None) -> None:
     p.add_argument("--heartbeat-interval-s", type=float, default=0.5)
     p.add_argument("--heartbeat-grace-s", type=float, default=5.0)
     args = p.parse_args(argv)
+
+    from repro.launch.cache import init_compile_cache
+
+    init_compile_cache()
 
     # chaos lanes arm per-process faults via $REPRO_FAULT_PLAN — installed
     # before any selection work so injected kills hit the intended site

@@ -90,7 +90,10 @@ class ModelConfig:
         total = 0
         if self.frontend == "tokens":
             total += self.vocab_size * d
-        total += self.n_codebooks * d * self.vocab_size  # unembed head(s)
+        if self.n_codebooks > 1 or not (
+            self.tie_embeddings and self.frontend == "tokens"
+        ):
+            total += self.n_codebooks * d * self.vocab_size  # unembed head(s)
         for kind in self.layer_kinds:
             if kind in ("attn", "local_attn"):
                 total += d * hd * (self.n_heads + 2 * self.n_kv_heads)

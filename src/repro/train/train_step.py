@@ -19,6 +19,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ops import resolve_impl
 from repro.models import loss_fn as model_loss_fn
 from repro.models import proxy_features
 from repro.models.config import ModelConfig
@@ -112,8 +113,7 @@ def make_select_step(
         accumulation either way); None keeps the model's COMPUTE_DTYPE
         (bf16) — mirroring ``lm_unembed_input_proxy``.
     """
-    if proxy_impl == "auto":
-        proxy_impl = "pallas" if jax.default_backend() == "tpu" else "einsum"
+    proxy_impl = resolve_impl(proxy_impl, "einsum")
     if proxy_impl == "pallas":
         from repro.models import proxy_features_fused
 

@@ -63,6 +63,7 @@ from repro.core.extract import ProxyExtractor
 from repro.core.refresh import AsyncRefresher, RefreshResult
 from repro.data.pipeline import CoresetSampler
 from repro.faults import FailurePolicy
+from repro.kernels.ops import resolve_impl
 from repro.models.config import ModelConfig
 from repro.optim.optimizers import Optimizer
 from repro.train.train_step import make_select_step, make_train_step
@@ -125,17 +126,22 @@ class Trainer:
         self.eval_dataset = eval_dataset
         self.optimizer = optimizer
         self.sampler = CoresetSampler(dataset.n_docs, tcfg.batch_size, tcfg.seed)
-        # No donate_argnums here: the AsyncRefresher snapshots params by
+        # Params are not donated: the AsyncRefresher snapshots them by
         # reference (immutable jax.Arrays), so a donating update would
-        # delete the worker's snapshot mid-refresh (core/refresh.py).
+        # delete the worker's snapshot mid-refresh (core/refresh.py).  The
+        # optimizer state has no other reader (checkpoints copy it to host
+        # before returning), so it is donated: without that, the old and new
+        # AdamW moments (2 × params in fp32) are live at once every step.
         self.train_step = jax.jit(
-            make_train_step(cfg, optimizer, microbatches=tcfg.microbatches)
+            make_train_step(cfg, optimizer, microbatches=tcfg.microbatches),
+            donate_argnums=(1,),
         )
         # Pipelined pool sweep (DESIGN.md §9): O(1) scan programs, prefetch,
         # device-resident features.  The extractor owns the select-step
         # compilation; megabatch 0 folds the whole default pool into one.
+        self.proxy_impl = resolve_impl(tcfg.proxy_impl, "einsum")
         self.extractor = ProxyExtractor(
-            make_select_step(cfg, proxy_impl=tcfg.proxy_impl),
+            make_select_step(cfg, proxy_impl=self.proxy_impl),
             dataset,
             tcfg.batch_size,
             megabatch=tcfg.extract_megabatch or max(1, tcfg.proxy_pool_batches),

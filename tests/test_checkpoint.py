@@ -70,9 +70,7 @@ def test_elastic_restore_with_shardings(tmp_path):
     """Restore places arrays with the provided (new-mesh) shardings."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.launch.mesh import compat_mesh
-
-    mesh = compat_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     mgr = CheckpointManager(str(tmp_path), keep=1)
     tree = _tree()
     mgr.save(3, tree)

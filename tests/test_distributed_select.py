@@ -23,9 +23,7 @@ SCRIPT = textwrap.dedent(
     from repro.core.craig import CraigConfig, CraigSelector
     from repro.core.engines import DeviceConfig, MatrixConfig, SparseConfig
 
-    from repro.launch.mesh import compat_mesh
-
-    mesh = compat_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     k = jax.random.PRNGKey(0)
     centers = jax.random.normal(k, (32, 16)) * 5
     assign = jax.random.randint(jax.random.PRNGKey(1), (1024,), 0, 32)
@@ -176,12 +174,12 @@ def test_even_shard_audit():
 def test_distributed_select_rejects_bad_counts_before_tracing():
     """distributed_select raises the informative audit errors even on a
     1-device mesh — they fire before shard_map ever traces."""
+    import jax
     import jax.numpy as jnp
 
     from repro.core.distributed import distributed_select
-    from repro.launch.mesh import compat_mesh
 
-    mesh = compat_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     feats = jnp.zeros((64, 4))
     with pytest.raises(ValueError, match="exceeds the shard pool size"):
         distributed_select(feats, mesh, r_local=65, r_final=8)

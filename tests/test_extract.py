@@ -248,7 +248,6 @@ SHARD_SCRIPT = textwrap.dedent(
     from repro.data.synthetic import TokenStream
     from repro.models import ModelConfig, init_params
     from repro.train import make_select_step
-    from repro.launch.mesh import compat_mesh
 
     cfg = ModelConfig(name="tiny", family="dense", n_layers=2, d_model=32,
                       n_heads=2, n_kv_heads=2, d_ff=64, vocab_size=128,
@@ -262,7 +261,7 @@ SHARD_SCRIPT = textwrap.dedent(
         ProxyExtractor(step, ds, 8, megabatch=8, prefetch=False)
         .extract(params, pool)
     )
-    mesh = compat_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     for mb in (1, 8):  # plan rounds batch counts up to shard multiples
         ex = ProxyExtractor(step, ds, 8, megabatch=mb, prefetch=True,
                             mesh=mesh)

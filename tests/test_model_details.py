@@ -107,3 +107,20 @@ def test_scan_vs_unrolled_stack_equivalence():
     l_u, _ = loss_fn(params_u, cfg_u, batch)
     # identical math; bf16 fusion order differs between scan and unrolled
     assert float(l_s) == pytest.approx(float(l_u), rel=2e-3)
+
+
+@pytest.mark.parametrize("tied", [True, False], ids=["tied", "untied"])
+def test_param_count_matches_init(tied):
+    """``param_count`` counts what ``init_params`` allocates: a tied config
+    has no separate unembed head.  The published Qwen3-1.7B (tied) has
+    1.72 B parameters."""
+    from repro.configs.registry import get_config
+
+    cfg = dataclasses.replace(get_config("qwen3-1.7b"), tie_embeddings=tied)
+    tree = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    n = sum(int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(tree))
+    tables = 1 if tied else 2  # embed (+ unembed), padded to padded_vocab
+    pad = (cfg.padded_vocab - cfg.vocab_size) * cfg.d_model * tables
+    assert n == cfg.param_count() + pad
+    if tied:
+        assert abs(cfg.param_count() - 1.72e9) / 1.72e9 < 0.02

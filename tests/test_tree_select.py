@@ -210,7 +210,8 @@ MESH_SCRIPT = textwrap.dedent(
     from repro.core.distributed import distributed_select
     from repro.distributed.tree_select import (
         TreeTopology, tree_mesh, tree_select_host, tree_select_mesh)
-    from repro.launch.mesh import compat_mesh
+    data_mesh = jax.make_mesh((8,), ("data",),
+                              axis_types=(jax.sharding.AxisType.Auto,))
 
     k = jax.random.PRNGKey(0)
     centers = jax.random.normal(k, (8, 16)) * 5.0
@@ -220,8 +221,7 @@ MESH_SCRIPT = textwrap.dedent(
 
     # depth-1 fp32 tree ≡ the existing two-round path, bit for bit
     topo1 = TreeTopology((8,))
-    ds = distributed_select(feats, compat_mesh((8,), ("data",)),
-                            r_local=6, r_final=10)
+    ds = distributed_select(feats, data_mesh, r_local=6, r_final=10)
     th = tree_select_host(feats, topo1, 6, 10, compress="none")
     tm = tree_select_mesh(feats, tree_mesh(topo1), topo1, 6, 10,
                           compress="none")
@@ -258,7 +258,7 @@ MESH_SCRIPT = textwrap.dedent(
         assert "not divisible" in str(e), e
     # mesh without the level axes is rejected
     try:
-        tree_select_mesh(feats, compat_mesh((8,), ("data",)), topo1, 6, 10)
+        tree_select_mesh(feats, data_mesh, topo1, 6, 10)
         raise SystemExit("expected ValueError for missing level axis")
     except ValueError as e:
         assert "missing level axis" in str(e), e
