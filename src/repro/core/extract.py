@@ -52,6 +52,7 @@ import numpy as np
 
 from repro.data.pipeline import Prefetcher
 from repro.faults import fault_value
+from repro.obs import span
 
 __all__ = ["ProxyExtractor", "make_scan_extract"]
 
@@ -155,12 +156,13 @@ class ProxyExtractor:
         mask: row i valid ⇔ i < n_pool, all padding at the tail).
         """
         b = self.batch_size
-        flat = np.arange(lo * b, lo * b + m * b) % len(pool_idx)
-        batch = self.dataset.batch(np.asarray(pool_idx)[flat])
-        return {
-            k: np.asarray(v).reshape((m, b) + np.shape(v)[1:])
-            for k, v in batch.items()
-        }
+        with span("extract.assemble"):
+            flat = np.arange(lo * b, lo * b + m * b) % len(pool_idx)
+            batch = self.dataset.batch(np.asarray(pool_idx)[flat])
+            return {
+                k: np.asarray(v).reshape((m, b) + np.shape(v)[1:])
+                for k, v in batch.items()
+            }
 
     # -- public API -------------------------------------------------------
 

@@ -54,6 +54,14 @@ or one inline re-run at the caller's next touch point
 (``'sync_fallback'`` — degrade to a synchronous refresh instead of skipping
 it).  Failure state is per *job*: an exhausted job never poisons the
 refresher — the next submit/ingest runs normally.
+
+Starting a job is a ``refresh.submit`` span on the caller's thread, the
+job a ``refresh.job`` span on whichever thread runs it, and its
+``on_complete`` a ``refresh.publish`` span, all three carrying the job's
+``version``: on the profiler's timeline the job on the worker thread is
+tied to its start and to the trainer's ``trainer.install`` of the same
+version (span catalog: ``repro.obs``).  ``RefreshResult.wall_time_s`` is
+read from ``time.perf_counter``, the clock that bounds the spans.
 """
 from __future__ import annotations
 
@@ -66,6 +74,7 @@ import jax
 import numpy as np
 
 from repro.faults import FailurePolicy, fault_point
+from repro.obs import span
 
 __all__ = ["AsyncRefresher", "RefreshResult"]
 
@@ -222,11 +231,19 @@ class AsyncRefresher:
                 return x.copy()
             return x
 
-        snap = jax.tree.map(snap_leaf, params) if snapshot else params
+        with span("refresh.submit", version=version):
+            snap = jax.tree.map(snap_leaf, params) if snapshot else params
+            self._start(version, lambda: self._work_fn(snap), "craig-refresh")
+        return version
+
+    def _start(self, version: int, fn: Callable[[], Any], name: str) -> None:
+        """Run the job of ``version`` inline (sync mode) or start it on the
+        worker thread ``<name>-v<version>`` (async mode)."""
 
         def job() -> None:
             try:
-                self._run_job(version, lambda: self._work_fn(snap))
+                with span("refresh.job", version=version):
+                    self._run_job(version, fn)
             except BaseException as e:  # noqa: BLE001 — never die silently
                 # _run_job routes everything through the policy; this outer
                 # capture only exists so a bug in the routing itself still
@@ -243,10 +260,9 @@ class AsyncRefresher:
             # non-daemon: the interpreter joins it at shutdown instead of
             # tearing down under a thread mid-XLA-dispatch (which aborts)
             self._thread = threading.Thread(
-                target=job, name=f"craig-refresh-v{version}", daemon=False
+                target=job, name=f"{name}-v{version}", daemon=False
             )
             self._thread.start()
-        return version
 
     # -- supervised job runner -----------------------------------------------
 
@@ -255,7 +271,7 @@ class AsyncRefresher:
         route the exhausted failure.  Runs on the worker thread in async
         mode, inline in sync mode."""
         policy = self._policy
-        t0 = time.time()
+        t0 = time.perf_counter()
         error: BaseException | None = None
         attempts = 0
         for attempt in range(policy.max_retries + 1):
@@ -269,7 +285,7 @@ class AsyncRefresher:
                     time.sleep(policy.backoff_s(attempt))
                 continue
             res = RefreshResult(
-                version, value, time.time() - t0, attempts=attempts
+                version, value, time.perf_counter() - t0, attempts=attempts
             )
             try:
                 if self._on_complete is not None:
@@ -277,11 +293,12 @@ class AsyncRefresher:
                     # vanish on the worker thread — but it is NOT retryable:
                     # the work succeeded, and re-running it could stage the
                     # same version twice
-                    self._on_complete(res)
+                    with span("refresh.publish", version=version):
+                        self._on_complete(res)
             except BaseException as e:  # noqa: BLE001 — routed via policy
                 self._exhaust(
                     RefreshResult(
-                        version, None, time.time() - t0, error=e,
+                        version, None, time.perf_counter() - t0, error=e,
                         attempts=attempts,
                     ),
                     fn,
@@ -293,7 +310,7 @@ class AsyncRefresher:
             return
         self._exhaust(
             RefreshResult(
-                version, None, time.time() - t0, error=error,
+                version, None, time.perf_counter() - t0, error=error,
                 attempts=attempts,
             ),
             fn,
@@ -341,30 +358,32 @@ class AsyncRefresher:
         if pending is None:
             return
         failed, fn = pending
-        t0 = time.time()
-        try:
-            value = fn()
-            res = RefreshResult(
-                failed.version,
-                value,
-                failed.wall_time_s + time.time() - t0,
-                attempts=failed.attempts + 1,
-                fell_back=True,
-            )
-            if self._on_complete is not None:
-                self._on_complete(res)
-            with self._lock:
-                self._result = res
-        except BaseException as e:  # noqa: BLE001 — re-raised at wait()
-            with self._lock:
-                self._result = RefreshResult(
+        with span("refresh.job", version=failed.version):
+            t0 = time.perf_counter()
+            try:
+                value = fn()
+                res = RefreshResult(
                     failed.version,
-                    None,
-                    failed.wall_time_s + time.time() - t0,
-                    error=e,
+                    value,
+                    failed.wall_time_s + time.perf_counter() - t0,
                     attempts=failed.attempts + 1,
                     fell_back=True,
                 )
+                if self._on_complete is not None:
+                    with span("refresh.publish", version=failed.version):
+                        self._on_complete(res)
+                with self._lock:
+                    self._result = res
+            except BaseException as e:  # noqa: BLE001 — re-raised at wait()
+                with self._lock:
+                    self._result = RefreshResult(
+                        failed.version,
+                        None,
+                        failed.wall_time_s + time.perf_counter() - t0,
+                        error=e,
+                        attempts=failed.attempts + 1,
+                        fell_back=True,
+                    )
 
     # -- streaming ingest (coalescing) ---------------------------------------
 
@@ -414,23 +433,8 @@ class AsyncRefresher:
             batch, self._pending = self._pending, []
         self._version += 1
         version = self._version
-
-        def job() -> None:
-            try:
-                self._run_job(version, lambda: self._ingest_fn(batch))
-            except BaseException as e:  # noqa: BLE001 — never die silently
-                with self._lock:
-                    self._result = RefreshResult(version, None, 0.0, error=e)
-
-        if self._mode == "sync":
-            job()
-            self._run_fallback_if_pending()
-            self._raise_if_failed()
-        else:
-            self._thread = threading.Thread(
-                target=job, name=f"craig-ingest-v{version}", daemon=False
-            )
-            self._thread.start()
+        with span("refresh.submit", version=version):
+            self._start(version, lambda: self._ingest_fn(batch), "craig-ingest")
         return version
 
     def reset_version(self, version: int) -> None:

@@ -41,9 +41,18 @@ Responsibilities (DESIGN.md §4):
     different mesh (elastic);
   * preemption: SIGTERM triggers an emergency checkpoint at the next step
     boundary (CPU-testable via ``request_preempt()``);
-  * straggler policy: per-step wall-clock watchdog — on the single-host
-    harness it only records violations; on a pod it feeds the
-    restart-from-checkpoint path.
+  * straggler policy: per-step watchdog on the dispatch plus the wait for
+    the step's loss — on the single-host harness it only records
+    violations; on a pod it feeds the restart-from-checkpoint path.  An
+    async refresh shares the device with the steps, so a step queued
+    behind a refresh's extraction waits for it and counts as slow: where
+    the refresh shares the chip, ``step_timeout_s`` must exceed one
+    extraction.
+
+The loop's boundaries and the refresh lifecycle are program spans
+(``trainer.step`` … ``refresh.publish``), recorded on the profiler's
+timeline under ``jax.profiler.start_trace``; their catalog is
+``repro.obs``.
 """
 from __future__ import annotations
 
@@ -65,6 +74,7 @@ from repro.data.pipeline import CoresetSampler
 from repro.faults import FailurePolicy
 from repro.kernels.ops import resolve_impl
 from repro.models.config import ModelConfig
+from repro.obs import span
 from repro.optim.optimizers import Optimizer
 from repro.train.train_step import make_select_step, make_train_step
 
@@ -98,7 +108,9 @@ class TrainerConfig:
     checkpoint_every: int = 50
     checkpoint_dir: str | None = None
     keep_checkpoints: int = 3
-    step_timeout_s: float | None = None  # straggler watchdog
+    step_timeout_s: float | None = None  # straggler watchdog on dispatch +
+    # the loss's wait; a step queued behind an async refresh's extraction on
+    # the same device waits for all of it, so set it above one extraction
     microbatches: int = 1
     seed: int = 0
     # Supervision for the refresh worker (DESIGN.md §12): retry/backoff per
@@ -248,14 +260,16 @@ class Trainer:
         pool_idx = self._pool_indices()
         labels = self._pool_labels(pool_idx)
         selector = CraigSelector(self.tcfg.craig)
-        feats = self.extractor.extract(params, pool_idx)
+        with span("refresh.extract"):
+            feats = self.extractor.extract(params, pool_idx)
         init = None
         prev = self._prev_selection
         if self.tcfg.warm_start_fraction > 0 and prev is not None:
             r0 = int(round(self.tcfg.warm_start_fraction * prev.size))
             if r0 > 0:
                 init = np.asarray(prev.indices[:r0])
-        sel = selector.select(feats, labels=labels, init_selected=init)
+        with span("refresh.select"):
+            sel = selector.select(feats, labels=labels, init_selected=init)
         self._prev_selection = sel
         return sel, pool_idx
 
@@ -327,9 +341,11 @@ class Trainer:
         # in arrival order (they are disjoint, cursor-ordered by _stream_submit)
         params = deltas[-1][0]
         new_idx = np.concatenate([np.asarray(d[1], np.int64) for d in deltas])
-        feats = np.asarray(
-            jax.device_get(self.extractor.extract(params, new_idx)), np.float32
-        )
+        with span("refresh.extract"):
+            feats = np.asarray(
+                jax.device_get(self.extractor.extract(params, new_idx)),
+                np.float32,
+            )
         labels = self._pool_labels(new_idx)
         if self._stream_sel is None:
             from repro.core.engines.streaming import StreamingSelector
@@ -381,10 +397,11 @@ class Trainer:
         """Epoch-boundary install point: wait out any in-flight selection
         (the deterministic deadline — normally it finished an epoch ago) and
         atomically swap the staged coreset in."""
-        t0 = time.time()
-        self.refresher.wait()
-        stall = time.time() - t0
-        p = self.sampler.install_pending()
+        with span("trainer.install", version=self.refresher.version):
+            t0 = time.perf_counter()
+            self.refresher.wait()
+            stall = time.perf_counter() - t0
+            p = self.sampler.install_pending()
         if p is None:
             return
         meta = p.get("meta") or {}
@@ -518,56 +535,67 @@ class Trainer:
     def run(self, n_steps: int) -> list[dict]:
         tc = self.tcfg
         for _ in range(n_steps):
-            epoch = self.sampler.epoch
-            # Refresh lifecycle, both modes at the same boundaries:
-            # install the previous trigger's selection at this epoch
-            # boundary, then (on cadence) snapshot params and kick off the
-            # next selection — async: in the background while this epoch
-            # trains on the stale coreset; sync: inline, blocking here.
-            if (
-                tc.use_craig
-                and tc.select_every_epochs > 0
-                and self.sampler.step_in_epoch == 0
-            ):
-                self._install_refresh()
+            with span("trainer.step", step=self.step + 1):
+                epoch = self.sampler.epoch
+                # Refresh lifecycle, both modes at the same boundaries:
+                # install the previous trigger's selection at this epoch
+                # boundary, then (on cadence) snapshot params and kick off
+                # the next selection — async: in the background while this
+                # epoch trains on the stale coreset; sync: inline, blocking
+                # here.
                 if (
-                    epoch % tc.select_every_epochs == 0
-                    and epoch != self._last_epoch_selected
+                    tc.use_craig
+                    and tc.select_every_epochs > 0
+                    and self.sampler.step_in_epoch == 0
                 ):
-                    if tc.streaming_ingest:
-                        self._stream_submit()
-                    else:
-                        self.refresher.submit(self.params)
-                    self._last_epoch_selected = epoch
+                    self._install_refresh()
+                    if (
+                        epoch % tc.select_every_epochs == 0
+                        and epoch != self._last_epoch_selected
+                    ):
+                        if tc.streaming_ingest:
+                            self._stream_submit()
+                        else:
+                            self.refresher.submit(self.params)
+                        self._last_epoch_selected = epoch
 
-            idx, w = self.sampler.next_batch()
-            batch = self.dataset.batch(idx)
-            batch["weights"] = w
-            batch.pop("indices", None)
-            t0 = time.time()
-            self.params, self.opt_state, metrics = self.train_step(
-                self.params, self.opt_state, batch
-            )
-            dt = time.time() - t0
-            if tc.step_timeout_s is not None and dt > tc.step_timeout_s:
-                self.straggler_events.append(self.step)
-            self.step += 1
-            self.metrics_log.append(
-                {
-                    "event": "step",
-                    "step": self.step,
-                    "loss": float(metrics["loss"]),
-                    "epoch": epoch,
-                    "time_s": dt,
-                }
-            )
-            if tc.eval_every and self.step % tc.eval_every == 0:
-                self.evaluate()
-            if self.ckpt is not None and self.step % tc.checkpoint_every == 0:
-                self._save(blocking=False)
-            if self._preempt:
-                self._save(blocking=True)
-                break
+                with span("trainer.batch"):
+                    idx, w = self.sampler.next_batch()
+                    batch = self.dataset.batch(idx)
+                    batch["weights"] = w
+                    batch.pop("indices", None)
+                # The watchdog times the dispatch and the wait for the
+                # step's loss, the loop's one sync: a slow device step shows.
+                t0 = time.perf_counter()
+                with span("trainer.dispatch"):
+                    self.params, self.opt_state, metrics = self.train_step(
+                        self.params, self.opt_state, batch
+                    )
+                with span("trainer.sync"):
+                    loss = float(metrics["loss"])
+                dt = time.perf_counter() - t0
+                if tc.step_timeout_s is not None and dt > tc.step_timeout_s:
+                    self.straggler_events.append(self.step)
+                self.step += 1
+                self.metrics_log.append(
+                    {
+                        "event": "step",
+                        "step": self.step,
+                        "loss": loss,
+                        "epoch": epoch,
+                        "time_s": dt,
+                    }
+                )
+                if tc.eval_every and self.step % tc.eval_every == 0:
+                    self.evaluate()
+                if (
+                    self.ckpt is not None
+                    and self.step % tc.checkpoint_every == 0
+                ):
+                    self._save(blocking=False)
+                if self._preempt:
+                    self._save(blocking=True)
+                    break
         if self.ckpt is not None:
             self.ckpt.wait()
         return self.metrics_log

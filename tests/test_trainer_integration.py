@@ -1,4 +1,6 @@
 """Trainer loop: CRAIG refresh schedule, preemption, restart equivalence."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -158,6 +160,37 @@ def test_straggler_watchdog_records():
     t = _trainer(None, step_timeout_s=0.0)  # everything is a "straggler"
     t.run(3)
     assert len(t.straggler_events) == 3
+
+
+class _SlowLoss:
+    """A loss whose value takes 50 ms to arrive, as a slow device step's."""
+
+    def __init__(self, loss):
+        self.loss = loss
+
+    def __float__(self):
+        time.sleep(0.05)
+        return float(self.loss)
+
+
+def test_straggler_watchdog_sees_a_slow_device_step():
+    """The watchdog times the dispatch and the wait for the step's loss:
+    a step whose result is slow to arrive is a straggler, though its
+    dispatch returns at once."""
+    t = _trainer(None, step_timeout_s=0.02, use_craig=False)
+    t.run(1)  # compiles
+    jitted = t.train_step
+
+    def slow_result(params, opt_state, batch):
+        params, opt_state, metrics = jitted(params, opt_state, batch)
+        return params, opt_state, {**metrics, "loss": _SlowLoss(metrics["loss"])}
+
+    t.train_step = slow_result
+    t.straggler_events.clear()
+    t.run(2)
+    assert t.straggler_events == [1, 2]
+    times = [m["time_s"] for m in t.metrics_log if m["event"] == "step"][1:]
+    assert len(times) == 2 and min(times) >= 0.05
 
 
 def test_no_craig_mode_plain_training():
