@@ -345,7 +345,7 @@ def ce_proxy(
     unembed: jax.Array,
     labels: jax.Array,
     *,
-    block_t: int = 128,
+    block_t: int | None = None,
     block_v: int = 512,
     interpret: bool | None = None,
     valid_v: int | None = None,
@@ -359,6 +359,13 @@ def ce_proxy(
     ``core.proxy.lm_unembed_input_proxy`` applies, so the two proxy paths
     agree on vocab-padded configs.  ``compute_dtype=bf16`` runs the MXU
     matmuls in bf16 with fp32 accumulation (softmax state stays fp32).
+
+    The label term y @ Wᵀ is a gather here, outside the kernel: the rows
+    ``Wᵀ[y_t]``, cast to ``compute_dtype`` and subtracted in the kernel's
+    finalize.  With a tied head W is the (V, D) embedding's transpose, so
+    XLA reads them as a row gather of the embedding.
+    ``block_t=None`` sizes the token tile from D and the compute dtype
+    (``ce_proxy.pick_block_t``: the largest ≤ 512 whose VMEM fits).
     """
     if interpret is None:
         interpret = interpret_default()
@@ -366,12 +373,17 @@ def ce_proxy(
     V = unembed.shape[1]
     vv = V if valid_v is None else valid_v
     bv = min(block_v, max(8, 1 << (V - 1).bit_length()))
+    if block_t is None:
+        block_t = _ce.pick_block_t(
+            D + (-D) % _LANE, bv, jnp.dtype(compute_dtype).itemsize
+        )
     bt = min(block_t, max(8, 1 << (T - 1).bit_length()))
+    wy = jnp.take(unembed.T, labels.reshape(T), axis=0).astype(compute_dtype)
     hp = _pad_dim(_pad_dim(hidden, 0, bt), 1, _LANE)
     wp = _pad_dim(_pad_dim(unembed, 0, _LANE), 1, bv)
-    lp = _pad_dim(labels.reshape(T), 0, bt)
+    wyp = _pad_dim(_pad_dim(wy, 0, bt), 1, _LANE)
     out = _ce.ce_proxy_pallas(
-        hp, wp, lp, block_t=bt, block_v=bv, interpret=interpret,
+        hp, wp, wyp, block_t=bt, block_v=bv, interpret=interpret,
         # mask everything past the real vocab, incl. the block padding,
         # unless nothing was padded at all
         valid_v=None if vv == wp.shape[1] else vv,
